@@ -186,10 +186,6 @@ def mult(ctx: TridentContext, x: AShare, y: AShare) -> AShare:
 # ---------------------------------------------------------------------------
 # Pi_DotP (Fig. 9) / matrix multiplication (batched, jnp.matmul semantics).
 # ---------------------------------------------------------------------------
-def _mm(_ring, a, b):
-    return jnp.matmul(a, b)
-
-
 _mm_shape = AL.matmul_shape
 
 
@@ -204,8 +200,7 @@ def dotp(ctx: TridentContext, x: AShare, y: AShare) -> AShare:
 def matmul(ctx: TridentContext, x: AShare, y: AShare) -> AShare:
     """Pi_MatMul = batched Pi_DotP: [[X]] @ [[Y]] with comm 3*ell per output
     element (paper Section VI-A: matrix ops decompose into dot products)."""
-    ring = ctx.ring
-    contract = lambda a, b: _mm(ring, a, b)
+    contract = ctx.ring.matmul
     return _mult_like(ctx, x, y, "Pi_DotP", contract=contract,
                       out_shape=_mm_shape(x.shape, y.shape))
 
@@ -315,8 +310,7 @@ def mult_tr(ctx: TridentContext, x: AShare, y: AShare,
 
 def matmul_tr(ctx: TridentContext, x: AShare, y: AShare) -> AShare:
     """[[X]] @ [[Y]] with fused truncation (the PPML workhorse)."""
-    ring = ctx.ring
-    contract = lambda a, b: _mm(ring, a, b)
+    contract = ctx.ring.matmul
     return mult_tr(ctx, x, y, contract=contract,
                    out_shape=_mm_shape(x.shape, y.shape),
                    name="Pi_MatMulTr")

@@ -9,8 +9,9 @@ per-kernel contracts):
   ppa_msb           fused local math of a boolean PPA/AND level
   prf_mask          counter-mode lambda-mask generation (keyed-lambda)
 
-ops.py holds the jit'd wrappers (interpret=True on CPU, see
-TRIDENT_KERNELS_COMPILED in docs/KERNELS.md); ref.py the pure-jnp oracles
+ops.py holds the jit'd wrappers: the u64 <-> u32-word boundary (words.py;
+Mosaic has no 64-bit types) and the platform's choice of interpreting
+(CPU) or compiling (TPU) every kernel; ref.py the pure-jnp oracles
 every kernel is asserted against (tests/test_kernels.py).  The party
 runtime routes its local compute through these via
 repro.runtime.kernel_backend (TRIDENT_RUNTIME_KERNELS=1).

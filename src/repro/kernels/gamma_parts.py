@@ -20,70 +20,76 @@ write one output per group:
     mult_terms(a, b, c, signs):  out[j] = sum_t signs[t] a[j,t] b[j,t] + c[j]
     and_terms(a, b, c):          out[j] = XOR_t (a[j,t] & b[j,t]) ^ c[j]
 
-Layouts: a, b are (J, T, n) stacked operand groups (J = pieces/parts this
-party computes this round, batched into ONE launch), c is (J, n).  Ring
-arithmetic mod 2^ell is exact in the integer dtype, and XOR/AND are
-bitwise, so both kernels are bit-exact against the per-term jnp evaluation
-order -- the property the runtime's cross-backend identity contract rests
-on (docs/KERNELS.md).
+Layouts: the operands arrive as u32 word tiles (``words.py``): a, b are
+(J, T, W, R, 128) stacked operand groups (J = pieces/parts this party
+computes this round, batched into ONE launch), c is (J, W, R, 128).  Ring
+arithmetic mod 2^ell on the words is exact, and XOR/AND are bitwise, so
+both kernels are bit-exact against the per-term jnp evaluation order --
+the property the runtime's cross-backend identity contract rests on
+(docs/KERNELS.md).
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import words as WD
 
 
 def _mult_terms_kernel(a_ref, b_ref, c_ref, out_ref, *, signs):
-    a = a_ref[...]                       # (J, T, bn) ring ints
-    b = b_ref[...]
-    acc = c_ref[...]                     # (J, bn)
-    for t, s in enumerate(signs):
-        term = a[:, t, :] * b[:, t, :]
-        acc = acc - term if s < 0 else acc + term
-    out_ref[...] = acc
+    n_groups, _, n_words = a_ref.shape[:3]
+    for j in range(n_groups):
+        acc = tuple(c_ref[j, w] for w in range(n_words))   # (br, 128) each
+        for t, s in enumerate(signs):
+            term = WD.mul(tuple(a_ref[j, t, w] for w in range(n_words)),
+                          tuple(b_ref[j, t, w] for w in range(n_words)))
+            acc = WD.sub(acc, term) if s < 0 else WD.add(acc, term)
+        for w in range(n_words):
+            out_ref[j, w] = acc[w]
 
 
 def _and_terms_kernel(a_ref, b_ref, c_ref, out_ref):
-    a = a_ref[...]
-    b = b_ref[...]
-    acc = c_ref[...]
-    for t in range(a.shape[1]):
-        acc = acc ^ (a[:, t, :] & b[:, t, :])
+    acc = c_ref[...]                                   # (J, W, br, 128)
+    for t in range(a_ref.shape[1]):
+        acc = acc ^ (a_ref[:, t] & b_ref[:, t])
     out_ref[...] = acc
 
 
-def _grouped_call(kernel, a, b, c, bn: int, interpret: bool):
-    J, T, n = a.shape
-    bn = min(bn, n)
-    assert n % bn == 0, (n, bn)
+def _grouped_call(kernel, a, b, c, br: int, interpret: bool):
+    J, T, W, R, lanes = a.shape
+    assert R % br == 0 and br % WD.SUBLANES == 0, (R, br)
     return pl.pallas_call(
         kernel,
-        grid=(n // bn,),
+        grid=(R // br,),
         in_specs=[
-            pl.BlockSpec((J, T, bn), lambda i: (0, 0, i)),
-            pl.BlockSpec((J, T, bn), lambda i: (0, 0, i)),
-            pl.BlockSpec((J, bn), lambda i: (0, i)),
+            pl.BlockSpec((J, T, W, br, lanes),
+                         lambda i: WD.block_index(0, 0, 0, i, 0)),
+            pl.BlockSpec((J, T, W, br, lanes),
+                         lambda i: WD.block_index(0, 0, 0, i, 0)),
+            pl.BlockSpec((J, W, br, lanes),
+                         lambda i: WD.block_index(0, 0, i, 0)),
         ],
-        out_specs=pl.BlockSpec((J, bn), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((J, n), a.dtype),
+        out_specs=pl.BlockSpec((J, W, br, lanes),
+                               lambda i: WD.block_index(0, 0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct(c.shape, c.dtype),
         interpret=interpret,
     )(a, b, c)
 
 
-@functools.partial(jax.jit, static_argnames=("signs", "bn", "interpret"))
-def mult_terms(a: jax.Array, b: jax.Array, c: jax.Array,
-               signs: tuple, bn: int = 512, interpret: bool = True):
-    """out[j] = sum_t signs[t] * a[j,t] * b[j,t] + c[j]  (mod 2^ell).
-    a, b: (J, T, n); c: (J, n); signs: static length-T tuple of +-1."""
+@functools.partial(jax.jit, static_argnames=("signs", "br", "interpret"))
+def mult_terms(a: jax.Array, b: jax.Array, c: jax.Array, signs: tuple,
+               br: int, interpret: bool):
+    """out[j] = sum_t signs[t] * a[j,t] * b[j,t] + c[j]  (mod 2^ell) on
+    word tiles: a, b (J, T, W, R, 128); c (J, W, R, 128); signs: static
+    length-T tuple of +-1; ``br`` rows per block."""
     kernel = functools.partial(_mult_terms_kernel, signs=signs)
-    return _grouped_call(kernel, a, b, c, bn, interpret)
+    return _grouped_call(kernel, a, b, c, br, interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("bn", "interpret"))
-def and_terms(a: jax.Array, b: jax.Array, c: jax.Array,
-              bn: int = 512, interpret: bool = True):
-    """out[j] = XOR_t (a[j,t] & b[j,t]) ^ c[j]  (bit-packed words)."""
-    return _grouped_call(_and_terms_kernel, a, b, c, bn, interpret)
+@functools.partial(jax.jit, static_argnames=("br", "interpret"))
+def and_terms(a: jax.Array, b: jax.Array, c: jax.Array, br: int,
+              interpret: bool):
+    """out[j] = XOR_t (a[j,t] & b[j,t]) ^ c[j] on word tiles."""
+    return _grouped_call(_and_terms_kernel, a, b, c, br, interpret)

@@ -26,24 +26,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .limb_matmul import limb_matmul
-
-
-def _ceil_to(d: int, blk: int) -> int:
-    """Smallest limb_matmul-legal extent >= d: d itself when a single block
-    covers it, else the next multiple of blk."""
-    return d if d <= blk else -(-d // blk) * blk
-
-
-def _pad2(x: jax.Array, rows: int, cols: int) -> jax.Array:
-    if x.shape == (rows, cols):
-        return x
-    return jnp.zeros((rows, cols), x.dtype).at[:x.shape[0],
-                                               :x.shape[1]].set(x)
+from .limb_matmul import ring_matmul
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def mpc_matmul_grid(xs: tuple, ys: tuple, interpret: bool = True):
+def mpc_matmul_grid(xs: tuple, ys: tuple, *, interpret: bool):
     """All-pairs ring matmuls in ONE limb pass: stack P left operands
     (M, K) by rows and Q right operands (K, N) by columns,
 
@@ -53,16 +40,12 @@ def mpc_matmul_grid(xs: tuple, ys: tuple, interpret: bool = True):
     Each operand's limbs are expanded once and every pairing runs at MXU
     rate -- this is how a party's whole same-round matmul workload (mm +
     its two online parts, or a gamma piece's term sum) becomes a single
-    kernel launch.  Zero-padding to block-legal extents is exact for
-    matmul, so arbitrary shapes are accepted."""
+    kernel launch.  ``ring_matmul`` zero-pads to block-legal extents
+    (exact for matmul), so arbitrary shapes are accepted."""
     P, Q = len(xs), len(ys)
-    M, K = xs[0].shape
+    M = xs[0].shape[0]
     N = ys[0].shape[1]
-    a = jnp.concatenate(xs, axis=0)                       # (P*M, K)
-    b = jnp.concatenate(ys, axis=1)                       # (K, Q*N)
-    rows, cols = _ceil_to(P * M, 64), _ceil_to(Q * N, 64)
-    kk = _ceil_to(K, 256)
-    p = limb_matmul(_pad2(a, rows, kk), _pad2(b, kk, cols),
+    p = ring_matmul(jnp.concatenate(xs, axis=0), jnp.concatenate(ys, axis=1),
                     interpret=interpret)
     return [[p[i * M:(i + 1) * M, j * N:(j + 1) * N] for j in range(Q)]
             for i in range(P)]
@@ -70,7 +53,7 @@ def mpc_matmul_grid(xs: tuple, ys: tuple, interpret: bool = True):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def mpc_matmul_fused(mx: jax.Array, lx: jax.Array, my: jax.Array,
-                     ly: jax.Array, interpret: bool = True):
+                     ly: jax.Array, *, interpret: bool):
     """mx: (M,K); lx: (3,M,K) lambda stack; my: (K,N); ly: (3,K,N).
     Returns (mm, cross, gamma_term):
         mm         = mx @ my

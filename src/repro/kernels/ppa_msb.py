@@ -7,7 +7,9 @@ is ~10 word-ops per element that XLA would otherwise run as separate
 HBM-roundtrip elementwise kernels.  This kernel fuses the whole level in
 VMEM: one read of the 8 input streams, one write of the m_z output.
 
-Layout: bit-sliced words; data stacks are (4, n) = (m, l1, l2, l3).
+Layout: bit-sliced words; data stacks are (4, n) = (m, l1, l2, l3), each
+word split into u32 tiles (words.py): the kernel is bitwise, so every
+32-bit word is independent.
 """
 from __future__ import annotations
 
@@ -17,11 +19,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import words as WD
+
 
 def _and_level_kernel(x_ref, y_ref, lamz_ref, zero_ref, out_ref):
     """m_z' parts of the word AND: out = (sum_i parts_i) ^ (m_x & m_y).
-    x/y: (4, bn) share stacks; lamz: (3, bn) fresh output lambdas;
-    zero: (3, bn) Pi_Zero shares randomizing gamma."""
+    x/y: (4, W, br, 128) share stacks; lamz: (3, ...) fresh output
+    lambdas; zero: (3, ...) Pi_Zero shares randomizing gamma."""
     x = x_ref[...]
     y = y_ref[...]
     lamz = lamz_ref[...]
@@ -36,34 +40,35 @@ def _and_level_kernel(x_ref, y_ref, lamz_ref, zero_ref, out_ref):
     p2 = (lx2 & my) ^ (mx & ly2) ^ g2 ^ lamz[1]
     p3 = (lx3 & my) ^ (mx & ly3) ^ g3 ^ lamz[2]
     m_z = p1 ^ p2 ^ p3 ^ (mx & my)
-    out_ref[...] = jnp.stack([m_z, lamz[0], lamz[1], lamz[2]])
+    for k, v in enumerate((m_z, lamz[0], lamz[1], lamz[2])):
+        out_ref[k] = v
 
 
-@functools.partial(jax.jit, static_argnames=("bn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def and_level(x: jax.Array, y: jax.Array, lamz: jax.Array,
-              zero: jax.Array, bn: int = 512, interpret: bool = True):
+              zero: jax.Array, interpret: bool):
     """x, y: (4, n) boolean share stacks -> (4, n) output share stack
-    (the AND's m_z plus its lambda components).  One fused VMEM pass."""
+    (the AND's m_z plus its lambda components).  One fused VMEM pass over
+    u32 word tiles (words.py)."""
     n = x.shape[1]
-    bn = min(bn, n)
-    assert n % bn == 0
-    return pl.pallas_call(
+    r, br = WD.tile_rows(n, 64)
+    tiles = [WD.to_tiles(v, r) for v in (x, y, lamz, zero)]
+    spec = lambda k: pl.BlockSpec(                           # noqa: E731
+        (k,) + tiles[0].shape[1:2] + (br, WD.LANES),
+        lambda i: WD.block_index(0, 0, i, 0))
+    out = pl.pallas_call(
         _and_level_kernel,
-        grid=(n // bn,),
-        in_specs=[
-            pl.BlockSpec((4, bn), lambda i: (0, i)),
-            pl.BlockSpec((4, bn), lambda i: (0, i)),
-            pl.BlockSpec((3, bn), lambda i: (0, i)),
-            pl.BlockSpec((3, bn), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((4, bn), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((4, n), x.dtype),
+        grid=(r // br,),
+        in_specs=[spec(4), spec(4), spec(3), spec(3)],
+        out_specs=spec(4),
+        out_shape=jax.ShapeDtypeStruct(tiles[0].shape, jnp.uint32),
         interpret=interpret,
-    )(x, y, lamz, zero)
+    )(*tiles)
+    return WD.from_tiles(out, n)
 
 
 def ppa_msb(x: jax.Array, y: jax.Array, lamz_levels: jax.Array,
-            zero_levels: jax.Array, interpret: bool = True) -> jax.Array:
+            zero_levels: jax.Array, interpret: bool) -> jax.Array:
     """Full Sklansky msb(x+y) driver over PUBLIC words (the kernel-level
     oracle target: each level's AND via the fused kernel with lambda = 0).
     x, y: (n,) ring words; returns the msb bit of x+y per word.

@@ -201,6 +201,8 @@ def _dealer_daemon_main(cfg, ctrl_qs, status_q):
     or a functools.partial of one)."""
     exporter = None
     try:
+        from ..runtime.net.cluster import boot_device
+        boot_device("dealer daemon")
         if cfg.get("trace") or tracing_enabled():
             install_tracer(Tracer("dealer"))
         tracer = get_tracer()

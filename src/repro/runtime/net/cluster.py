@@ -77,6 +77,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import multiprocessing as mp
+import os
 import queue as _queue
 import socket
 import threading
@@ -325,18 +326,69 @@ def _ctrl_loop(ctrl_q, bank, rank):
                   f"{traceback.format_exc()}")
 
 
-def _daemon_main(rank, endpoints, cfg, task_q, ctrl_q, out_q):
+def boot_device(label: str, expect=None):
+    """The device this party (or dealer) computes on, checked at boot.
+
+    JAX falls back to the CPU, with only a warning, when it cannot
+    initialize the accelerator -- for example because another process
+    holds the chip.  A daemon left on the CPU that way refuses to serve:
+    the CPU is used only where ``JAX_PLATFORMS`` asks for it.  ``expect``
+    is the device a pinned daemon was given."""
+    import jax.numpy as jnp
+    dev = next(iter(jnp.zeros(()).devices()))
+    if expect is not None and dev != expect:
+        raise RuntimeError(f"{label} was given {expect} but computes on "
+                           f"{dev}")
+    asked = os.environ.get("JAX_PLATFORMS", "").split(",")
+    if expect is None and dev.platform == "cpu" and "cpu" not in asked:
+        raise RuntimeError(
+            f"{label} initialized a {dev.platform} backend "
+            f"({dev.device_kind}) without JAX_PLATFORMS=cpu: no "
+            f"accelerator was available to it; refusing to serve")
+    return dev
+
+
+def describe_device(dev) -> str:
+    return f"{dev.platform}:{dev.id} ({dev.device_kind})"
+
+
+class _PinnedDaemon(threading.Thread):
+    """A party daemon as a thread of this process, computing on one
+    device: the deployment shape where one process drives every chip of
+    a host (only one process may hold a TPU host's chips)."""
+
+    def __init__(self, device, args):
+        super().__init__(target=self._main, args=args, daemon=True,
+                         name=f"party-P{args[0]}")
+        self.device = device
+
+    def _main(self, *args):
+        import jax
+        with jax.default_device(self.device):
+            _daemon_main(*args, device=self.device)
+
+    def terminate(self) -> None:
+        """A thread cannot be killed; a hung daemon thread ends with the
+        process (it is a daemon thread)."""
+
+
+def _daemon_main(rank, endpoints, cfg, task_q, ctrl_q, out_q, device=None):
     exporter = None
     try:
-        # install the labeled tracer BEFORE the transport exists so the
-        # mesh's MeasuredTransport captures it (env TRIDENT_TRACE=1 also
-        # lands here: spawned children inherit the environment)
-        if cfg.get("trace") or tracing_enabled():
-            install_tracer(Tracer(f"party-P{rank}", rank=rank))
-        # the metrics registry is ALWAYS on (cheap counters); install it
-        # labeled and BEFORE the transport for the same capture reason.
-        # cfg["metrics"] only decides whether an HTTP exporter serves it.
-        install_registry(MetricsRegistry(f"party-P{rank}", rank=rank))
+        # a daemon process installs its own labeled tracer and registry;
+        # pinned daemon threads share their process's
+        if device is None:
+            # install the labeled tracer BEFORE the transport exists so
+            # the mesh's MeasuredTransport captures it (env TRIDENT_TRACE=1
+            # also lands here: spawned children inherit the environment)
+            if cfg.get("trace") or tracing_enabled():
+                install_tracer(Tracer(f"party-P{rank}", rank=rank))
+            # the metrics registry is ALWAYS on (cheap counters); install
+            # it labeled and BEFORE the transport for the same capture
+            # reason.  cfg["metrics"] only decides whether an HTTP
+            # exporter serves it.
+            install_registry(MetricsRegistry(f"party-P{rank}", rank=rank))
+        dev = boot_device(f"party daemon P{rank}", expect=device)
         metrics_port = None
         if cfg.get("metrics"):
             from ...obs.exporter import MetricsExporter
@@ -363,7 +415,7 @@ def _daemon_main(rank, endpoints, cfg, task_q, ctrl_q, out_q):
             threading.Thread(target=_ctrl_loop, args=(ctrl_q, bank, rank),
                              daemon=True, name=f"ctrl-P{rank}").start()
         out_q.put(("ready", rank, len(bank) if bank is not None else 0,
-                   metrics_port))
+                   metrics_port, describe_device(dev)))
         while True:
             task = task_q.get()
             if task is None:
@@ -392,14 +444,22 @@ def _daemon_main(rank, endpoints, cfg, task_q, ctrl_q, out_q):
 
 
 class PartyCluster:
-    """Four long-lived party daemons over a persistent TCP mesh."""
+    """Four long-lived party daemons over a persistent TCP mesh.
+
+    By default each daemon is a spawned process on the device its JAX
+    picks (``boot_device`` refuses a silent CPU fallback).  ``devices``
+    (four JAX devices) instead runs party i as a thread of this process
+    pinned to ``devices[i]``: one process driving every chip of a host,
+    which is how four parties get four chips where a chip cannot be
+    handed to a child process."""
 
     def __init__(self, *, ring: Ring = RING64,
                  timeout: float = DEFAULT_TIMEOUT, tampers=(),
                  net_model=None, prep_path: str | None = None,
                  live_prep: bool = False,
                  live_ahead: int = DEFAULT_LIVE_AHEAD,
-                 trace: bool = False, metrics: bool = False):
+                 trace: bool = False, metrics: bool = False,
+                 devices=None):
         if live_prep and prep_path is not None:
             raise ValueError(
                 "live_prep streams into an initially empty bank; "
@@ -407,6 +467,15 @@ class PartyCluster:
         ctx = mp.get_context("spawn")
         trace = trace or tracing_enabled()
         metrics = metrics or metrics_enabled()
+        if devices is not None:
+            devices = list(devices)
+            if len(devices) != 4:
+                raise ValueError(f"devices: one per party (4), got "
+                                 f"{len(devices)}")
+            if trace or metrics:
+                raise ValueError("pinned daemon threads share their "
+                                 "process's tracer and registry: trace/"
+                                 "metrics need daemon processes")
         cfg = {
             "ring": ring, "timeout": timeout, "tampers": list(tampers),
             "net_model": net_model, "prep_path": prep_path,
@@ -422,6 +491,8 @@ class PartyCluster:
         self.metrics = metrics
         # rank -> exporter HTTP port (metrics=True; filled from ready acks)
         self.metrics_ports: dict = {}
+        # rank -> "platform:id (kind)" each daemon computes on (ready acks)
+        self.daemon_devices: dict = {}
         # per-task trace chunks from every rank (plus whatever the caller
         # extends with, e.g. the DealerDaemon's chunks)
         self.trace_chunks: list = []
@@ -455,21 +526,20 @@ class PartyCluster:
                                  for _ in range(4)] if live_prep else None)
             self._out_q = ctx.Queue()
             endpoints = [("127.0.0.1", p) for p in _free_ports(4)]
+            args = [(rank, endpoints, cfg, self._task_qs[rank],
+                     self.ctrl_queues[rank] if live_prep else None,
+                     self._out_q) for rank in range(4)]
             self._procs = [
-                ctx.Process(target=_daemon_main,
-                            args=(rank, endpoints, cfg,
-                                  self._task_qs[rank],
-                                  self.ctrl_queues[rank] if live_prep
-                                  else None,
-                                  self._out_q),
-                            daemon=True)
-                for rank in range(4)]
+                ctx.Process(target=_daemon_main, args=a, daemon=True)
+                if devices is None else _PinnedDaemon(devices[a[0]], a)
+                for a in args]
             for p in self._procs:
                 p.start()
             try:
                 acks = self._collect(lambda item: item[0] == "ready",
                                      self.timeout, fail_fast=True)
                 self.metrics_ports = {a[1]: a[3] for a in acks}
+                self.daemon_devices = {a[1]: a[4] for a in sorted(acks)}
                 break
             except Exception as e:
                 self._teardown_procs()

@@ -390,7 +390,7 @@ def dotp(rt: FourPartyRuntime, x: DistAShare, y: DistAShare) -> DistAShare:
 
 @traced_protocol("matmul")
 def matmul(rt: FourPartyRuntime, x: DistAShare, y: DistAShare) -> DistAShare:
-    contract = lambda a, b: jnp.matmul(a, b)
+    contract = rt.ring.matmul
     return _mult_like(rt, x, y, contract=contract,
                       out_shape=matmul_shape(x.shape, y.shape), name="matmul",
                       kind="matmul")
@@ -406,7 +406,7 @@ def mult_tr(rt: FourPartyRuntime, x: DistAShare, y: DistAShare) -> DistAShare:
 def matmul_tr(rt: FourPartyRuntime, x: DistAShare,
               y: DistAShare) -> DistAShare:
     """[[X]] @ [[Y]] with fused truncation (the PPML workhorse)."""
-    contract = lambda a, b: jnp.matmul(a, b)
+    contract = rt.ring.matmul
     return _mult_like(rt, x, y, contract=contract,
                       out_shape=matmul_shape(x.shape, y.shape), truncate=True,
                       name="matmultr", kind="matmul")

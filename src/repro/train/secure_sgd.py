@@ -119,23 +119,25 @@ def nn_task(net: PML.MLPNet | None = None, lr: float = 0.25) -> SGDTask:
 # World runners (one step; step-indexed seeds).
 # ---------------------------------------------------------------------------
 def make_engine(world: str, seed: int, *, ring: Ring = RING64,
-                transport=None, prep=None) -> Engine:
+                transport=None, prep=None, kernel_backend=None) -> Engine:
     if world == "joint":
         return TridentEngine(make_context(ring, seed=seed),
                              nonlinear="newton")
     if world == "runtime":
-        return RuntimeEngine(FourPartyRuntime(ring, seed=seed,
-                                              transport=transport,
-                                              prep=prep))
+        return RuntimeEngine(FourPartyRuntime(
+            ring, seed=seed, transport=transport, prep=prep,
+            kernel_backend=kernel_backend))
     raise ValueError(f"unknown world {world!r}")
 
 
 def run_step(task: SGDTask, params: dict, batch: tuple, *, step: int,
              base_seed: int = 0, world: str = "joint", ring: Ring = RING64,
-             transport=None, prep=None):
-    """One training step in `world` from the step-indexed seed."""
+             transport=None, prep=None, kernel_backend=None):
+    """One training step in `world` from the step-indexed seed
+    (``kernel_backend`` picks the runtime world's local compute)."""
     eng = make_engine(world, seed_for_step(base_seed, step), ring=ring,
-                      transport=transport, prep=prep)
+                      transport=transport, prep=prep,
+                      kernel_backend=kernel_backend)
     return task.run(eng, params, batch)
 
 

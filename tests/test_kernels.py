@@ -110,3 +110,22 @@ def test_prf_mask_statistics():
     bits = np.unpackbits(out.view(np.uint8))
     assert 0.47 < bits.mean() < 0.53
     assert np.all(np.bitwise_or.reduce(out) == np.uint64(2**64 - 1))
+
+
+@pytest.mark.parametrize("signs", [(1, 1, 1), (1, -1, -1)])
+@pytest.mark.parametrize("dtype", [np.uint32, np.uint64])
+def test_mult_terms_signed_sum(rng, dtype, signs):
+    """out[j] = c[j] + sum_t signs[t] a[j,t] b[j,t] mod 2^ell, with the
+    borrows and carries of the u32-word arithmetic exercised by full-range
+    operands (n not a multiple of the 128-lane rows)."""
+    J, T, n = 2, len(signs), 1000
+    hi = np.iinfo(dtype).max
+    a = rng.randint(0, hi, (J, T, n), dtype=np.uint64).astype(dtype)
+    b = rng.randint(0, hi, (J, T, n), dtype=np.uint64).astype(dtype)
+    c = rng.randint(0, hi, (J, n), dtype=np.uint64).astype(dtype)
+    got = ops.mult_terms(jnp.asarray(a), jnp.asarray(b), jnp.asarray(c),
+                         signs)
+    want = c.copy()
+    for t, s in enumerate(signs):
+        want = want + a[:, t] * b[:, t] if s > 0 else want - a[:, t] * b[:, t]
+    np.testing.assert_array_equal(np.asarray(got), want)

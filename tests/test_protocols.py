@@ -249,3 +249,50 @@ class TestRing32:
         z = PR.mult_tr(ctx32, xs, xs)   # 2^34 * 2^13 >> 2^31: wraps
         v = ctx32.ring.decode(z.reveal())
         assert np.all(np.isfinite(v))   # wraps silently, never NaN
+
+
+# ---------------------------------------------------------------------------
+# Ring matmul: exact mod 2^ell against Python integers.
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("batched", [False, True])
+@pytest.mark.parametrize("fill", ["random", "all_ones"])
+@pytest.mark.parametrize("k", [1, 784, 980])
+@pytest.mark.parametrize("ring", [RING32, RING64], ids=["ell32", "ell64"])
+def test_ring_matmul_exact(ring, k, fill, batched):
+    """Ring.matmul == Python-integer matmul mod 2^ell, with jnp.matmul
+    batching/broadcasting; all-ones operands (2^ell - 1) are the worst
+    case for every digit and carry."""
+    rng = np.random.RandomState(k)
+    sa, sb = ((2, 1, 4, k), (3, k, 5)) if batched else ((4, k), (k, 5))
+    if fill == "random":
+        a = rng.randint(0, 1 << 62, sa, dtype=np.uint64) * 4 + 3
+        b = rng.randint(0, 1 << 62, sb, dtype=np.uint64) * 4 + 1
+        a, b = a.astype(ring.np_dtype), b.astype(ring.np_dtype)
+    else:
+        a = np.full(sa, (1 << ring.ell) - 1, ring.np_dtype)
+        b = np.full(sb, (1 << ring.ell) - 1, ring.np_dtype)
+    got = np.asarray(ring.matmul(jnp.asarray(a), jnp.asarray(b)))
+    want = np.matmul(a.astype(object), b.astype(object)) % (1 << ring.ell)
+    assert got.dtype == ring.np_dtype and got.shape == want.shape
+    assert (got.astype(object) == want).all()
+
+
+@pytest.mark.parametrize("ring", [RING32, RING64], ids=["ell32", "ell64"])
+def test_encode_decode_host_and_traced_agree(ring):
+    """Host values encode/decode on the host in IEEE f64 (XLA:TPU only
+    emulates f64); traced values take the device path.  On the CPU the
+    two are the same function, bit for bit, including ties."""
+    import jax
+    rng = np.random.RandomState(3)
+    x = np.concatenate([rng.randn(1000) * 30.0,
+                        (np.arange(-8, 8) + 0.5) / ring.scale])
+    host = np.asarray(ring.encode(x))
+    traced = np.asarray(jax.jit(ring.encode)(x))
+    assert host.dtype == ring.np_dtype
+    assert np.array_equal(host, traced)
+    assert np.array_equal(host, np.round(x * ring.scale).astype(
+        ring.np_sdtype).astype(ring.np_dtype))
+    back = ring.decode(host)
+    assert isinstance(back, np.ndarray)
+    assert np.array_equal(back, np.asarray(jax.jit(ring.decode)(host)))
+    np.testing.assert_allclose(back, x, atol=0.5 / ring.scale)

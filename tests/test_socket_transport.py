@@ -161,3 +161,35 @@ class TestServeOverSockets:
         ref = np.maximum(queries @ W1, 0.0)
         got = np.stack([np.asarray(p) for p in preds])
         assert np.abs(got - ref).max() < 0.02
+
+
+class TestDaemonDevices:
+    """Every party daemon reports the device it computes on, and none
+    computes on a CPU it was not asked to use."""
+
+    def test_cpu_refused_unless_asked_for(self, monkeypatch):
+        from repro.runtime.net.cluster import boot_device
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        assert boot_device("P0").platform == "cpu"
+        monkeypatch.delenv("JAX_PLATFORMS")
+        with pytest.raises(RuntimeError, match="initialized a cpu backend"):
+            boot_device("party daemon P0")
+
+    def test_pinned_threads_match_local(self):
+        """devices= runs the parties as threads pinned to the given
+        devices (one process driving every chip): same mesh, same
+        bits as the in-process runtime."""
+        import jax
+        from repro.runtime.net import PartyCluster
+        dev = jax.devices()[0]
+        _, local_out = local_reference()
+        with PartyCluster(devices=[dev] * 4, timeout=300) as cluster:
+            results = cluster.submit(nn_program, seed=SEED)
+            assert cluster.daemon_devices == {
+                r: f"{dev.platform}:{dev.id} ({dev.device_kind})"
+                for r in range(4)}
+        for r in results:
+            assert np.array_equal(np.asarray(r.result), local_out)
+            assert not r.abort
+        with pytest.raises(ValueError, match="one per party"):
+            PartyCluster(devices=[dev])
