@@ -5,9 +5,8 @@ CI runs this against netbench's ``--trace-out`` artifact: the trace must
 parse, carry spans from ALL FOUR party ranks (``--expect-dealer`` also
 requires the dealer's process), and contain the core span taxonomy
 (wire rounds + sends; protocol spans ride on the same buffer).  A thin
-gate -- the exact-equality trace-consistency asserts live in netbench and
-tests/test_obs.py -- but it fails loudly if a rank's chunks ever stop
-making it back over the result channel.
+gate, but it fails loudly if a rank's chunks ever stop making it back
+over the result channel.
 
     python scripts/check_trace.py netbench_trace.json [--expect-dealer]
 """

@@ -5,7 +5,8 @@ Two halves:
   * **Tracing** (off by default; ``TRIDENT_TRACE=1`` /
     ``PartyCluster(trace=True)`` / ``netbench --trace``): every
     instrumented seam records span/instant events that merge into one
-    Perfetto-viewable cluster timeline.
+    Perfetto-viewable cluster timeline; ``Tracer(sink="profiler")``
+    puts the spans into the JAX profiler's trace instead.
   * **Live metrics** (always on): the same seams update a process-local
     ``MetricsRegistry`` -- counters, gauges, fixed-edge histograms --
     unconditionally; ``TRIDENT_METRICS=1`` / ``PartyCluster(metrics=True)``
@@ -14,7 +15,7 @@ Two halves:
 
 See docs/OBSERVABILITY.md for the span/metric taxonomy and workflows.
 """
-from repro.obs.merge import merge_chunks, merged_link_bits, write_chrome_trace
+from repro.obs.merge import merge_chunks, write_chrome_trace
 from repro.obs.metrics import metrics_snapshot, round_wall_ms
 from repro.obs.registry import (
     METRICS_ENV,
@@ -31,7 +32,9 @@ from repro.obs.registry import (
     snapshot_value,
 )
 from repro.obs.tracer import (
+    NULL_SPAN,
     NULL_TRACER,
+    PROFILER_PREFIX,
     RECV_SPAN_MIN_S,
     NullTracer,
     Stopwatch,
@@ -52,8 +55,10 @@ __all__ = [
     "Histogram",
     "METRICS_ENV",
     "MetricsRegistry",
+    "NULL_SPAN",
     "NULL_TRACER",
     "NullTracer",
+    "PROFILER_PREFIX",
     "RECV_SPAN_MIN_S",
     "Stopwatch",
     "TRACE_ENV",
@@ -64,7 +69,6 @@ __all__ = [
     "install_registry",
     "install_tracer",
     "merge_chunks",
-    "merged_link_bits",
     "metrics_enabled",
     "metrics_snapshot",
     "round_wall_ms",

@@ -70,20 +70,3 @@ def write_chrome_trace(path, chunks) -> dict:
         json.dump(doc, fh)
     return doc
 
-
-def merged_link_bits(chunks) -> dict:
-    """Per-link traced bytes from ONE process's perspective, keyed
-    ``"src->dst"`` -> phase -> bits.  Under the replicated-program model
-    every daemon simulates the full mesh, so chunks from different ranks
-    each carry the complete per-link picture; this helper takes the
-    maximum per cell rather than summing, and callers compare it against
-    ``MeasuredTransport.per_link()``."""
-    out: dict = {}
-    for chunk in chunks:
-        if not chunk:
-            continue
-        for link, per in chunk.get("link_bits", {}).items():
-            cell = out.setdefault(link, {})
-            for phase, bits in per.items():
-                cell[phase] = max(cell.get(phase, 0), bits)
-    return out

@@ -18,19 +18,22 @@ Enablement:
   * ``install_tracer(Tracer(...))`` -- explicit, per-process (what
     ``PartyCluster(trace=True)`` does inside each daemon).
 
-Each process buffers its own events against its own ``perf_counter``
-clock and remembers the perf->epoch offset taken at tracer creation;
-``drain()`` snapshots the buffer into a self-describing **chunk** (label,
-rank, epoch, events, per-link traced bytes) that can cross a process
-boundary as a plain pickle/JSON value.  ``repro.obs.merge`` aligns chunks
-from the four party daemons plus the dealer into one Chrome trace-event
-timeline (docs/OBSERVABILITY.md).
+A tracer has one of two sinks:
 
-The tracer double-books wire traffic on purpose: ``wire_send`` keeps its
-own per-(src, dst)-per-phase bit totals, and the trace-consistency tests
-assert they equal ``MeasuredTransport.per_link()`` exactly -- an
-end-to-end cross-check that the trace saw every byte the transport
-measured.
+  * ``"buffer"`` (the default): each process buffers its own events
+    against its own ``perf_counter`` clock and remembers the perf->epoch
+    offset taken at tracer creation; ``drain()`` snapshots the buffer
+    into a self-describing **chunk** (label, rank, epoch, events) that
+    can cross a process boundary as a plain pickle/JSON value.
+    ``repro.obs.merge`` aligns chunks from the four party daemons plus
+    the dealer into one Chrome trace-event timeline
+    (docs/OBSERVABILITY.md);
+  * ``"profiler"``: every context span becomes a
+    ``jax.profiler.TraceAnnotation`` (a TraceMe) named ``trident.<span>``,
+    so it lands in the JAX profiler's ``.xplane.pb`` on the profiler's
+    host clock, on the line of the thread that ran it; span arguments
+    become TraceMe metadata.  Nothing is buffered: instants, counters and
+    already-timed ``raw_span``s have no TraceMe form and are dropped.
 """
 from __future__ import annotations
 
@@ -39,9 +42,13 @@ import functools
 import os
 import threading
 import time
-from collections import defaultdict
 
 TRACE_ENV = "TRIDENT_TRACE"
+SINKS = ("buffer", "profiler")
+
+# the profiler sink's span names carry this prefix, so a reader of the
+# .xplane.pb tells the program's spans from a harness's own annotations
+PROFILER_PREFIX = "trident."
 
 # recv spans are only recorded when the receive actually blocked this
 # long -- every recv as a span would drown the timeline in no-wait noise
@@ -53,6 +60,26 @@ def tracing_enabled() -> bool:
     return os.environ.get(TRACE_ENV, "") == "1"
 
 
+class NullSpan:
+    """The span of a disabled tracer: entering, setting arguments and
+    discarding are no-ops."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **args) -> None:
+        pass
+
+    def discard(self) -> None:
+        pass
+
+
+NULL_SPAN = NullSpan()
+
+
 class NullTracer:
     """The disabled tracer: every hook is a no-op.  Instrumented code
     guards with ``if tracer.enabled:`` so the off path costs one branch."""
@@ -62,7 +89,7 @@ class NullTracer:
     rank = None
 
     def span(self, name, cat="", **args):
-        return _NULL_SPAN
+        return NULL_SPAN
 
     def raw_span(self, name, cat, t0, dur, **args) -> None:
         pass
@@ -73,57 +100,119 @@ class NullTracer:
     def counter(self, name, value, cat="") -> None:
         pass
 
-    def wire_send(self, src, dst, tag, bits, phase, rnd) -> None:
-        pass
-
     def drain(self):
         return None
 
 
-_NULL_SPAN = contextlib.nullcontext()
 NULL_TRACER = NullTracer()
 
 
-class Tracer:
-    """An enabled per-process trace buffer.
+class _BufferSpan:
+    """A context span of the buffer sink: timed from enter to exit, its
+    arguments completed at exit with ``set``; ``discard`` drops it."""
 
-    Events are dicts ``{ph, name, cat, ts, dur?, tid, args?}`` with
-    ``ts``/``dur`` in ``perf_counter`` seconds; ``ph`` follows the Chrome
-    trace-event phases ("X" span, "i" instant, "C" counter).  Appends are
-    lock-protected: a party daemon's control thread (live prep) and task
-    thread trace into the same buffer.
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+
+    def __init__(self, tracer, name, cat, args):
+        self._tracer, self._name, self._cat = tracer, name, cat
+        self._args = args
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        if self._tracer is not None:
+            self._tracer.raw_span(self._name, self._cat, self._t0,
+                                  time.perf_counter() - self._t0,
+                                  **self._args)
+        return False
+
+    def set(self, **args) -> None:
+        self._args.update(args)
+
+    def discard(self) -> None:
+        self._tracer = None
+
+
+def _metadata(args: dict) -> dict:
+    # TraceMe metadata holds numbers and strings; None means "not known"
+    return {k: v for k, v in args.items() if v is not None}
+
+
+class _ProfilerSpan:
+    """A context span of the profiler sink: a TraceMe open while the work
+    runs.  A TraceMe cannot be withdrawn, so ``discard`` is a no-op."""
+
+    __slots__ = ("_trace_me",)
+
+    def __init__(self, name, args):
+        from jax.profiler import TraceAnnotation
+        self._trace_me = TraceAnnotation(PROFILER_PREFIX + name,
+                                         **_metadata(args))
+
+    def __enter__(self):
+        self._trace_me.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._trace_me.__exit__(*exc)
+        return False
+
+    def set(self, **args) -> None:
+        self._trace_me.set_metadata(**_metadata(args))
+
+    def discard(self) -> None:
+        pass
+
+
+class Tracer:
+    """An enabled per-process tracer (see the module docstring's sinks).
+
+    Buffered events are dicts ``{ph, name, cat, ts, dur?, tid, args?}``
+    with ``ts``/``dur`` in ``perf_counter`` seconds; ``ph`` follows the
+    Chrome trace-event phases ("X" span, "i" instant, "C" counter).
+    Appends are lock-protected: a party daemon's control thread (live
+    prep) and task thread trace into the same buffer.
     """
 
     enabled = True
 
-    def __init__(self, label: str | None = None, rank: int | None = None):
+    def __init__(self, label: str | None = None, rank: int | None = None,
+                 sink: str = "buffer"):
+        if sink not in SINKS:
+            raise ValueError(f"unknown tracer sink {sink!r}; one of {SINKS}")
         self.label = label or f"proc-{os.getpid()}"
         self.rank = rank
+        self.sink = sink
         self._epoch = time.time() - time.perf_counter()
         self._lock = threading.Lock()
         self._events: list[dict] = []
-        # traced wire bytes, (src, dst) -> phase -> bits: the tracer-side
-        # twin of MeasuredTransport.link_bits (asserted equal in tests)
-        self._link_bits: dict = defaultdict(lambda: defaultdict(int))
 
     # -- recording ---------------------------------------------------------
     def _append(self, ev: dict) -> None:
+        if self.sink != "buffer":
+            return
         ev["tid"] = threading.get_ident()
         with self._lock:
             self._events.append(ev)
 
-    @contextlib.contextmanager
     def span(self, name: str, cat: str = "", **args):
-        t0 = time.perf_counter()
-        try:
-            yield self
-        finally:
-            self.raw_span(name, cat, t0, time.perf_counter() - t0, **args)
+        """A context span over the work inside the ``with``; the object
+        entered has ``set(**args)`` for arguments known only at exit."""
+        if self.sink == "buffer":
+            return _BufferSpan(self, name, cat, args)
+        if cat == "protocol":
+            # buffered, a protocol span is the bare protocol ("mult") and
+            # its cat says what it is; a TraceMe has only its name
+            name = f"protocol.{name}"
+        return _ProfilerSpan(name, args)
 
     def raw_span(self, name: str, cat: str, t0: float, dur: float,
                  **args) -> None:
-        """Record an already-timed span (callers that measure their own
-        wall clock, e.g. the transport's round scopes)."""
+        """Record an already-timed span (callers that only know after the
+        fact whether the interval is worth a span, e.g. a blocking recv).
+        The profiler sink drops it."""
         ev = {"ph": "X", "name": name, "cat": cat, "ts": t0, "dur": dur}
         if args:
             ev["args"] = args
@@ -140,27 +229,7 @@ class Tracer:
         self._append({"ph": "C", "name": name, "cat": cat,
                       "ts": time.perf_counter(), "args": {"value": value}})
 
-    def wire_send(self, src: int, dst: int, tag: str, bits: int,
-                  phase: str, rnd: int) -> None:
-        """One measured transport send: accumulate the traced per-link
-        bytes and drop an instant on the timeline.  Zero-bit sends (hash
-        copies) are timeline events but never link-bit cells, mirroring
-        ``MeasuredTransport``'s own ``if bits:`` accounting guard."""
-        if bits:
-            with self._lock:
-                self._link_bits[(src, dst)][phase] += bits
-        self.instant("send", cat="wire.send", src=src, dst=dst, tag=tag,
-                     bits=bits, phase=phase, round=rnd)
-
     # -- snapshotting ------------------------------------------------------
-    def link_bits(self) -> dict:
-        """Traced bytes so far: {(src, dst): {phase: bits}} -- directly
-        comparable to ``MeasuredTransport.per_link()`` (phases absent from
-        the trace are simply missing keys)."""
-        with self._lock:
-            return {link: dict(per) for link, per
-                    in sorted(self._link_bits.items())}
-
     def drain(self) -> dict:
         """Snapshot-and-reset: returns a self-describing trace chunk and
         clears the buffer (per-task deltas in the cluster daemons).  The
@@ -168,12 +237,8 @@ class Tracer:
         dump to JSON."""
         with self._lock:
             events, self._events = self._events, []
-            links = {f"{s}->{d}": dict(per)
-                     for (s, d), per in sorted(self._link_bits.items())}
-            self._link_bits.clear()
         return {"label": self.label, "rank": self.rank,
-                "epoch": self._epoch, "events": events,
-                "link_bits": links}
+                "epoch": self._epoch, "events": events}
 
 
 # ---------------------------------------------------------------------------
@@ -213,14 +278,27 @@ def ensure_tracer(label: str, rank: int | None = None):
 # ---------------------------------------------------------------------------
 # Instrumentation helpers.
 # ---------------------------------------------------------------------------
+def _prep_session(rt):
+    store = getattr(rt.prep, "store", None)
+    return getattr(store, "meta", {}).get("session") \
+        if store is not None else None
+
+
 def traced_protocol(name: str):
     """Decorate a runtime protocol entry point (``fn(rt, ...)``): the
     live metrics registry UNCONDITIONALLY counts the call and the number
     of CheckLedger verdicts the four parties recorded during it; when the
-    runtime's tracer is enabled, the call additionally becomes a span
+    runtime's tracer is enabled, the call additionally runs inside a span
     carrying prep attribution (mode + PrepStore session) and the same
     check count.  Untraced: two counter adds, then straight through."""
     from .registry import get_registry
+
+    def count_checks(reg, rt, checks0) -> int:
+        checks = sum(len(p.ledger.checks) for p in rt.parties) - checks0
+        if checks:
+            reg.counter("trident_protocol_checks_total",
+                        "CheckLedger verdicts recorded").inc(checks)
+        return checks
 
     def deco(fn):
         @functools.wraps(fn)
@@ -230,45 +308,49 @@ def traced_protocol(name: str):
                         "runtime protocol entries", protocol=name).inc()
             checks0 = sum(len(p.ledger.checks) for p in rt.parties)
             tr = rt.tracer
-            t0 = time.perf_counter() if tr.enabled else 0.0
-            try:
-                return fn(rt, *args, **kwargs)
-            finally:
-                checks = sum(len(p.ledger.checks)
-                             for p in rt.parties) - checks0
-                if checks:
-                    reg.counter("trident_protocol_checks_total",
-                                "CheckLedger verdicts recorded").inc(checks)
-                if tr.enabled:
-                    store = getattr(rt.prep, "store", None)
-                    session = getattr(store, "meta", {}).get("session") \
-                        if store is not None else None
-                    tr.raw_span(name, "protocol", t0,
-                                time.perf_counter() - t0,
-                                prep=rt.prep.mode,
-                                session=session, checks=checks)
+            if not tr.enabled:
+                try:
+                    return fn(rt, *args, **kwargs)
+                finally:
+                    count_checks(reg, rt, checks0)
+            with tr.span(name, "protocol", prep=rt.prep.mode,
+                         session=_prep_session(rt)) as sp:
+                try:
+                    return fn(rt, *args, **kwargs)
+                finally:
+                    sp.set(checks=count_checks(reg, rt, checks0))
         return wrapper
     return deco
 
 
 @contextlib.contextmanager
 def timed(stats, *attrs, span: str | None = None, cat: str = "serve",
-          **span_args):
+          cpu: str | None = None, **span_args):
     """Accumulate the elapsed wall-clock into ``stats.<attr>`` for every
     attr named (the one consolidated spelling of the old inline
     ``t0 = perf_counter(); ...; stats.x += perf_counter() - t0``
-    bookkeeping), and -- when the process tracer is on -- record the same
-    interval as a span."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        for attr in attrs:
-            setattr(stats, attr, getattr(stats, attr) + dt)
-        tr = get_tracer()
-        if tr.enabled and span is not None:
-            tr.raw_span(span, cat, t0, dt, **span_args)
+    bookkeeping), and the calling thread's CPU time (``time.thread_time``)
+    into ``stats.<cpu>`` when ``cpu`` is given.  When the process tracer
+    is on, the work runs inside a span ``span`` that gets the thread CPU
+    as ``cpu_s`` at exit.  Yields the span (``NULL_SPAN`` untraced), whose
+    ``set`` adds arguments known only at exit."""
+    tr = get_tracer()
+    sp = tr.span(span, cat, **span_args) \
+        if tr.enabled and span is not None else NULL_SPAN
+    with sp:
+        # the CPU interval is read inside the wall interval: cpu <= wall
+        t0 = time.perf_counter()
+        c0 = time.thread_time() if cpu is not None else 0.0
+        try:
+            yield sp
+        finally:
+            if cpu is not None:
+                dc = time.thread_time() - c0
+                setattr(stats, cpu, getattr(stats, cpu) + dc)
+                sp.set(cpu_s=dc)
+            dt = time.perf_counter() - t0
+            for attr in attrs:
+                setattr(stats, attr, getattr(stats, attr) + dt)
 
 
 class Stopwatch:

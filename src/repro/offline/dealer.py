@@ -27,7 +27,7 @@ import logging
 import time
 
 from ..core.ring import RING64, Ring
-from ..obs import get_tracer
+from ..obs import NULL_SPAN, get_tracer
 from .store import DealPrep, PrepBank, PrepError, PrepStore
 
 _log = logging.getLogger(__name__)
@@ -43,6 +43,7 @@ class DealReport:
     wall_s: float
     abort: bool
     summary: dict
+    cpu_s: float = 0.0                  # the dealing thread's CPU time
 
 
 def deal(program, *, ring: Ring = RING64, seed: int = 0, transport=None,
@@ -65,10 +66,20 @@ def deal(program, *, ring: Ring = RING64, seed: int = 0, transport=None,
                           prep=DealPrep(store), **(runtime_kwargs or {}))
     entries_before = len(store)
     before = tp.totals()                 # transports may be reused/stacked
-    t0 = time.perf_counter()
-    program(rt)
-    wall = time.perf_counter() - t0
-    totals = tp.totals()
+    tracer = get_tracer()
+    sp = tracer.span("deal", "prep", seed=seed,
+                     session=store.meta.get("session")) \
+        if tracer.enabled else NULL_SPAN
+    with sp:
+        t0 = time.perf_counter()
+        c0 = time.thread_time()         # read inside the wall interval
+        program(rt)
+        cpu = time.thread_time() - c0
+        wall = time.perf_counter() - t0
+        totals = tp.totals()
+        offline_bits = totals["offline"]["bits"] - before["offline"]["bits"]
+        sp.set(entries=len(store) - entries_before,
+               offline_bits=offline_bits, cpu_s=cpu)
     online = {k: totals["online"][k] - before["online"][k]
               for k in totals["online"]}
     if online["bits"] or online["rounds"]:
@@ -78,26 +89,20 @@ def deal(program, *, ring: Ring = RING64, seed: int = 0, transport=None,
     if bool(rt.abort_flag()):
         raise PrepError("dealer pass aborted: offline-phase consistency "
                         "checks failed")
-    offline_bits = totals["offline"]["bits"] - before["offline"]["bits"]
     _log.debug("deal pass: %d entries, %d offline rounds, %d offline bits, "
                "%.3fs (seed %d, session %s)",
                len(store) - entries_before,
                totals["offline"]["rounds"] - before["offline"]["rounds"],
                offline_bits, wall, seed, store.meta.get("session"))
-    tracer = get_tracer()
-    if tracer.enabled:
-        tracer.raw_span("deal", "prep", t0, wall, seed=seed,
-                        session=store.meta.get("session"),
-                        entries=len(store) - entries_before,
-                        offline_bits=offline_bits)
     return store, DealReport(
         entries=len(store) - entries_before,
         offline_rounds=totals["offline"]["rounds"]
         - before["offline"]["rounds"],
-        offline_bits=totals["offline"]["bits"] - before["offline"]["bits"],
+        offline_bits=offline_bits,
         wall_s=wall,
         abort=False,
         summary=store.summary(),
+        cpu_s=cpu,
     )
 
 

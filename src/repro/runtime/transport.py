@@ -41,7 +41,7 @@ import math
 import time
 from collections import defaultdict, deque
 
-from repro.obs import RECV_SPAN_MIN_S, get_registry, get_tracer
+from repro.obs import NULL_SPAN, RECV_SPAN_MIN_S, get_registry, get_tracer
 
 PHASES = ("offline", "online")
 
@@ -185,10 +185,9 @@ class MeasuredTransport(Transport):
         self._tampers: list[TamperRule] = []
         self._forbidden: set[str] = set()
         # observability: the process tracer (NULL_TRACER unless enabled),
-        # plus per-phase round indices / open-scope timing for round spans
+        # plus per-phase round indices / open-scope bits for round spans
         self.tracer = get_tracer()
         self._round_index = {p: 0 for p in PHASES}
-        self._round_t0 = {p: 0.0 for p in PHASES}
         self._round_bits0 = {p: 0 for p in PHASES}
         # live metrics (always on): the registry double-books the wire --
         # trident_wire_bits_total must equal per_link() exactly, the
@@ -254,40 +253,45 @@ class MeasuredTransport(Transport):
     @contextlib.contextmanager
     def round(self, phase: str):
         assert phase in PHASES, phase
-        tracing = self.tracer.enabled
-        if self._round_depth[phase] == 0:
+        outermost = self._round_depth[phase] == 0
+        sp = NULL_SPAN
+        if outermost:
             self._round_traffic[phase] = False
-            if tracing:
-                self._round_t0[phase] = time.perf_counter()
+            if self.tracer.enabled:
+                # the span covers the whole outermost scope incl. the
+                # backend flush -- the measured cost of one round
+                sp = self.tracer.span(f"round[{phase}]", "wire.round",
+                                      phase=phase)
                 self._round_bits0[phase] = self.phase_bits[phase]
         self._round_depth[phase] += 1
-        try:
-            yield self
-        finally:
-            self._round_depth[phase] -= 1
-            if self._round_depth[phase] == 0:
-                if self._round_traffic[phase]:
-                    self._frames.add(phase, 1)
-                    c = self._m_rounds.get(phase)
-                    if c is None:
-                        c = self._m_rounds[phase] = self.metrics.counter(
-                            "trident_wire_round_scopes_total",
-                            "traffic-bearing outermost round scopes "
-                            "(parallel-overlapped scopes each count, so "
-                            ">= the analytic round tally)", phase=phase)
-                    c.inc()
-                self._round_flush(phase)
-                if tracing and self._round_traffic[phase]:
-                    # span covers the whole outermost scope incl. the
-                    # backend flush -- the measured cost of one round
-                    t0 = self._round_t0[phase]
-                    self.tracer.raw_span(
-                        f"round[{phase}]", "wire.round", t0,
-                        time.perf_counter() - t0, phase=phase,
-                        index=self._round_index[phase],
-                        bits=self.phase_bits[phase]
-                        - self._round_bits0[phase])
-                    self._round_index[phase] += 1
+        with sp:
+            try:
+                yield self
+            finally:
+                self._round_depth[phase] -= 1
+                if outermost:
+                    self._close_round(phase, sp)
+
+    def _close_round(self, phase: str, sp) -> None:
+        if self._round_traffic[phase]:
+            self._frames.add(phase, 1)
+            c = self._m_rounds.get(phase)
+            if c is None:
+                c = self._m_rounds[phase] = self.metrics.counter(
+                    "trident_wire_round_scopes_total",
+                    "traffic-bearing outermost round scopes "
+                    "(parallel-overlapped scopes each count, so "
+                    ">= the analytic round tally)", phase=phase)
+            c.inc()
+        self._round_flush(phase)
+        if sp is NULL_SPAN:
+            return
+        if self._round_traffic[phase]:
+            sp.set(index=self._round_index[phase],
+                   bits=self.phase_bits[phase] - self._round_bits0[phase])
+            self._round_index[phase] += 1
+        else:
+            sp.discard()                # a scope that moved nothing
 
     def parallel(self, phases=PHASES):
         return self._frames.parallel(phases)
@@ -325,8 +329,9 @@ class MeasuredTransport(Transport):
                 src=src, dst=dst)
         c.inc()
         if self.tracer.enabled:
-            self.tracer.wire_send(src, dst, tag, bits, phase,
-                                  self._round_index[phase])
+            self.tracer.instant("send", cat="wire.send", src=src, dst=dst,
+                                tag=tag, bits=bits, phase=phase,
+                                round=self._round_index[phase])
         payload = self._apply_tamper(src, dst, tag, payload)
         self._put(src, dst, tag, payload)
 

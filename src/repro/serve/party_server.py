@@ -66,7 +66,10 @@ class PartyServeStats:
     offline_bits: int = 0
     compute_s: float = 0.0
     online_compute_s: float = 0.0      # online-only wall (prep modes)
+    online_compute_cpu_s: float = 0.0  # the online thread's CPU time in it
     offline_deal_s: float = 0.0        # dealer wall (overlapped: pipelined)
+    offline_deal_cpu_s: float = 0.0    # the dealer thread's CPU time in it
+    prep_wait_s: float = 0.0           # online side blocked on the dealer
     modeled_s: dict = dataclasses.field(
         default_factory=lambda: {"offline": 0.0, "online": 0.0})
     link_online_bits: dict = dataclasses.field(default_factory=dict)
@@ -176,13 +179,23 @@ class PartyPredictionServer:
 
     def _run_batch_pipelined(self, X, n):
         from ..offline import OnlinePrep
-        _, store, drep = self._pipe.next_store()
+        waited = self.stats.prep_wait_s
+        with obs.timed(self.stats, "prep_wait_s", span="prep.wait",
+                       cat="prep") as sp:
+            session, store, drep = self._pipe.next_store()
+            sp.set(session=session)
+        obs.get_registry().counter(
+            "trident_prep_wait_us_total",
+            "wall-clock blocked acquiring prep material (us)").inc(
+            (self.stats.prep_wait_s - waited) * 1e6)
         self.stats.offline_deal_s += drep.wall_s
+        self.stats.offline_deal_cpu_s += drep.cpu_s
         base, tp = self._transport()
         tp.forbid_phase("offline")
         rt = FourPartyRuntime(self.ring, transport=tp,
                               prep=OnlinePrep(store))
         with obs.timed(self.stats, "online_compute_s", "compute_s",
+                       cpu="online_compute_cpu_s",
                        span="serve.batch.online", queries=n):
             preds = np.asarray(self.predict_fn(rt, X))
         self.stats.queries += n
