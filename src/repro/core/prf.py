@@ -15,18 +15,23 @@ uniform ring elements.  This is the SAME function the fused Pallas kernel
 ``kernels/prf_mask.py`` computes (asserted bit-exact in tests), which is
 what lets the runtime's pallas kernel backend generate -- and the prep seam
 REgenerate -- lambda masks in-kernel while staying bit-identical to the
-joint simulation and the jnp backend.  It stands in for the paper's
+joint simulation and the jnp backend.  A jnp draw (``prf_bits`` /
+``prf_bounded``) is one compiled program per (shape, ring, shift), the
+key and the counter its operands.  It stands in for the paper's
 fixed-key AES-CTR F_k; pseudorandomness is the only property the protocols
 use (docs/KERNELS.md).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Iterable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ..obs.registry import get_registry
 from .ring import Ring
 
 PARTIES = (0, 1, 2, 3)
@@ -113,14 +118,27 @@ def _numel(shape) -> int:
     return n
 
 
+@functools.partial(jax.jit, static_argnames=("shape", "ring", "shift"))
+def _draw(key, counter, *, shape, ring, shift):
+    """One PRF draw as one XLA program per (shape, ring, shift); the key
+    and the counter are operands, so every draw of a shape reuses it."""
+    if isinstance(counter, jax.core.Tracer):      # tracing, not disable_jit
+        get_registry().counter(
+            "trident_prf_compiles_total",
+            "PRF draw programs traced: one per (shape, ring, shift)").inc()
+    out = squares_stream(squares_key(key, counter), _numel(shape))
+    out = out.reshape(shape).astype(ring.dtype)
+    return out >> shift if shift else out
+
+
 def prf_bits(key: jax.Array, counter: int, shape, ring: Ring) -> jax.Array:
     """F_k(counter) -> uniform ring elements of `shape` (counter-mode PRF)."""
-    out = squares_stream(squares_key(key, counter), _numel(shape))
-    return out.reshape(shape).astype(ring.dtype)
+    return _draw(key, np.uint32(counter), shape=tuple(map(int, shape)),
+                 ring=ring, shift=0)
 
 
 def prf_bounded(key: jax.Array, counter: int, shape, ring: Ring,
                 bits: int) -> jax.Array:
     """Uniform over [0, 2^bits) embedded in the ring (used by guarded BitExt)."""
-    raw = prf_bits(key, counter, shape, ring)
-    return raw >> (ring.ell - bits)
+    return _draw(key, np.uint32(counter), shape=tuple(map(int, shape)),
+                 ring=ring, shift=ring.ell - bits)

@@ -72,6 +72,81 @@ class TestPrfParity:
 
 
 # ---------------------------------------------------------------------------
+# The compiled PRF draw: one program per (shape, ring, shift), bit-identical
+# to the eager composition, the counter an operand.
+# ---------------------------------------------------------------------------
+def _eager_draw(key, counter, shape, ring):
+    n = int(np.prod(shape))
+    out = prf.squares_stream(prf.squares_key(key, counter), n)
+    return out.reshape(shape).astype(ring.dtype)
+
+
+class TestCompiledDraw:
+    @pytest.mark.parametrize("bits", [None, 60, 39])
+    @pytest.mark.parametrize("counter", [0, 322, 2 ** 31 + 5])
+    @pytest.mark.parametrize("shape", [(11,), (128, 1), (36, 128, 1),
+                                       (128, 784)])
+    @pytest.mark.parametrize("ring", [RING64, RING32])
+    def test_draw_equals_eager_composition(self, ring, shape, counter,
+                                           bits):
+        """bits None is prf_bits; otherwise prf_bounded, whose shift
+        (ell - bits) the 32-bit ring keeps at the 64-bit ring's value."""
+        key = jax.random.key(5)
+        want = _eager_draw(key, counter, shape, ring)
+        if bits is None:
+            got = prf.prf_bits(key, counter, shape, ring)
+        else:
+            bits -= 64 - ring.ell
+            got = prf.prf_bounded(key, counter, shape, ring, bits)
+            want = want >> (ring.ell - bits)
+        assert got.dtype == want.dtype and got.shape == tuple(shape)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+    def test_redeal_compiles_nothing_and_matches_eager_deal(self):
+        """Once a deal has traced the program's draws, a second deal that
+        runs it twice traces none anew -- its second pass draws at counters
+        the first deal never used, which a static counter would trace --
+        and a deal's material equals the same seed dealt with every jit
+        disabled."""
+        from repro.nn.runtime_engine import RuntimeEngine
+        from repro.obs import get_registry
+        from repro.train import paper_ml as PML
+
+        net = PML.MLPNet(features=8, layers=(4, 2))
+        rng = np.random.RandomState(3)
+        params = PML.mlp_net_init(rng, net)
+        X = rng.randn(4, 8)
+
+        def program(rt):
+            eng = RuntimeEngine(rt)
+            sh = {k: eng.from_plain(params[k]) for k in sorted(params)}
+            return PML.mlp_net_predict(eng, sh, net, eng.from_plain(X))
+
+        def twice(rt):
+            return program(rt), program(rt)
+
+        reg = get_registry()
+        store, _ = deal(program, seed=21)
+        compiles = reg.total("trident_prf_compiles_total")
+        launches = reg.total("trident_kernel_launches_total")
+        deal(twice, seed=21)
+        assert reg.total("trident_kernel_launches_total") > launches
+        assert reg.total("trident_prf_compiles_total") == compiles
+        with jax.disable_jit():
+            eager, _ = deal(program, seed=21)
+        assert store.tags() == eager.tags() and len(store) > 0
+        for tag in store.tags():
+            kind, parts = store._entries[tag]
+            ekind, eparts = eager._entries[tag]
+            assert kind == ekind
+            flat = jax.tree_util.tree_leaves_with_path(parts)
+            eflat = jax.tree_util.tree_leaves_with_path(eparts)
+            assert [p for p, _ in flat] == [p for p, _ in eflat], tag
+            for (_, a), (_, b) in zip(flat, eflat):
+                assert a.dtype == b.dtype and np.array_equal(a, b), tag
+
+
+# ---------------------------------------------------------------------------
 # Backend resolution (the TRIDENT_RUNTIME_KERNELS env seam).
 # ---------------------------------------------------------------------------
 class TestBackendResolution:

@@ -91,6 +91,18 @@ def test_squares_prf_compiles(one_chip, no_cache):
                 lambda S: (S(1),))
 
 
+@pytest.mark.parametrize("shape,shift", [((FEATURES, HIDDEN), 0),
+                                         ((BATCH, HIDDEN), 4)])
+def test_prf_draw_compiles(one_chip, no_cache, shape, shift):
+    """One PRF draw as the dealer runs it: subset key and counter in."""
+    def draw(key, counter):
+        return prf._draw(key, counter, shape=shape, ring=RING64,
+                         shift=shift)
+    key = jax.random.key(0)
+    compile_for(one_chip, draw, lambda S: (S(*key.shape, dtype=key.dtype),
+                                           S(dtype=jnp.uint32)))
+
+
 # every kernel the "pallas" backend launches, at the NN's widths, compiled
 # by Mosaic: name -> (fn, operands)
 N = BATCH * HIDDEN
