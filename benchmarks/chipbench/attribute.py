@@ -25,39 +25,25 @@ import run as harness
 
 
 def attribute(args) -> dict:
-    cell, config, traffic, limits = harness.load_cell(args.workload)
-    if args.rehearse:
-        config, traffic = harness.rehearsal_sizes(config, traffic)
-    harness.prepare_environment()
-    import compare
-    import drivers
+    cell = harness.open_cell(args.workload, args.rehearse)
     import program_spans
     import trace_reduce
-    harness.check_devices(cell["chips"], args.rehearse)
     from repro import obs
-    from repro.core.ring import Ring
 
     if args.program_spans:
         obs.install_tracer(obs.Tracer("chipbench", sink="profiler"))
     log_dir = tempfile.mkdtemp(prefix="chipbench-")
     try:
-        out = drivers.DRIVERS[traffic["driver"]](drivers.Cell(
-            config=config, traffic=traffic, seed=args.seed,
-            seconds=args.seconds,
-            ring=Ring(ell=config["ring_ell"], frac=config["ring_frac"]),
-            window=harness.Window(log_dir),
-            clock_start=harness.CLOCK_START))
+        out = cell.run(args.seed, args.seconds, harness.Window(log_dir))
         path = trace_reduce.find_xplane(log_dir)
         reduced = None if args.rehearse else trace_reduce.reduce(path)
         spans = program_spans.reduce(path)
     finally:
         shutil.rmtree(log_dir, ignore_errors=True)
-    checks = {k: {"value": v, "limit": limits["limits"][k]}
-              for k, v in out.numbers.items()}
     return {
-        "correct": out.failed == 0 and compare.passed(checks),
+        "correct": cell.judge(out)[0],
         "program_spans_on": bool(args.program_spans),
-        "window_s": out.window_s, "window_batches": out.layer["batches"],
+        "window_s": out.window_s, "window_batches": out.layer.get("batches"),
         "end_to_end": out.end_to_end, "layer": out.layer,
         "per_layer": program_spans.per_layer(spans),
         "program": spans,

@@ -1,25 +1,21 @@
-"""The comparison that decides ``correct``: the number a run compares with
-the plain reference.
-
-score_gap: the largest |probability - reference probability| over every
-class of every query of every batch the window answered, each times that
-query's reference smx denominator ``sum relu(z) + 1e-2``.  The smx divides
-every score by that denominator, which can be as small as 1e-2, so the
-bare probability gap of a query whose scores are all near 0 is its
-scores' fixed-point rounding magnified up to 100 times; the product undoes
-that and reads the error in the scores' own units.
+"""The decision on ``correct``: each number that a cell's model compares
+with its plain reference (``models/<model>.py`` ``numbers``) against its
+limit in ``limits/<cell>.json``.
 """
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
-import reference
 
-
-def serve_numbers(params: dict, queries, probs) -> dict:
-    want, denominator = reference.predict(params, queries)
-    gap = np.abs(np.asarray(probs) - want)
-    return {"score_gap": float(np.max(gap * denominator))}
+def checks(numbers: dict, limits: dict) -> tuple[dict, list]:
+    """(each limit's number beside it, the limits a run gave no number
+    for).  A number that is missing reads as infinite: it missed its
+    limit."""
+    got = {k: {"value": numbers.get(k, math.inf), "limit": v}
+           for k, v in limits["limits"].items()}
+    return got, sorted(set(limits["limits"]) - set(numbers))
 
 
 def passed(checks: dict) -> bool:

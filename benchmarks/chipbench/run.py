@@ -6,17 +6,21 @@
 
 The cell, its configuration and its traffic are found by name from
 ``BENCHMARK.json`` at the checkout's root: ``configs/<config>.json``,
-``traffic/<traffic>.json`` and ``limits/<cell>.json`` here.  The run sets
-up and warms up (``setup_s``), measures for ``--seconds``, compares what
-the window produced with the plain reference (``compare.py``), and prints
-one JSON line as the last line of stdout.  With ``--trace 1`` the window
+``traffic/<traffic>.json`` and ``limits/<cell>.json`` here.  The
+configuration's ``"model"`` names ``models/<model>.py`` and the traffic's
+``"driver"`` names ``drivers/<driver>.py`` (``open_cell``).  The driver sets
+up and warms up (``setup_s``), measures for ``--seconds`` and compares
+what the window produced with the model's plain reference; the numbers
+are held to their limits (``compare.py``), and the run prints one JSON
+line as the last line of stdout.  With ``--trace 1`` the window
 runs under the JAX profiler and the line carries the per-layer metrics,
 each read by ``metrics/<metric>.py``; with ``--trace 0`` it carries the
-end-to-end metrics.
+end-to-end metrics that the cell's driver measures (``end_to_end``).
 
 It runs on the first TPU chip and exits non-zero, printing no result,
-where JAX finds none.  ``--rehearse`` runs on the CPU instead, at a tiny
-width and batch, and prints no device metric.
+where JAX finds none.  ``--rehearse`` runs on the CPU instead, at the
+model's tiny cut (``rehearsal``), and prints no device metric: only
+counts and host-clock CPU timings.
 """
 from __future__ import annotations
 
@@ -26,6 +30,7 @@ CLOCK_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
+import dataclasses  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -33,11 +38,10 @@ import pathlib  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import types  # noqa: E402
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
-REHEARSE_WIDTH = 16
-REHEARSE_BATCH = 16
 
 
 class BenchError(RuntimeError):
@@ -72,21 +76,29 @@ def load_cell(name: str) -> tuple[dict, dict, dict, dict]:
     return cell, config, traffic, limits
 
 
-def rehearsal_sizes(config: dict, traffic: dict) -> tuple[dict, dict]:
-    """The cell cut to a CPU rehearsal: hidden widths and batch 16."""
-    layers = config["layers"]
-    config = dict(config, layers=[min(w, REHEARSE_WIDTH)
-                                  for w in layers[:-1]] + [layers[-1]])
-    return config, dict(traffic, batch=min(traffic["batch"], REHEARSE_BATCH))
+def plugin(kind: str, name: str):
+    """The module ``<kind>/<name>.py`` here: a driver, a model or a
+    metric's reader, found by its name and nothing else."""
+    path = HERE / kind / f"{name}.py"
+    if not path.is_file():
+        raise BenchError(f"no {kind}/{name}.py in {HERE}")
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_{kind}_{name.replace('.', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def resolve(config: dict, traffic: dict):
+    """(driver, model): the modules that the traffic's ``"driver"`` and
+    the configuration's ``"model"`` name.  Call it once the environment is
+    prepared: a model may import JAX."""
+    return (plugin("drivers", traffic["driver"]),
+            plugin("models", config["model"]))
 
 
 def read_metric(name: str, run: dict):
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_')}",
-        HERE / "metrics" / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(run)
+    return plugin("metrics", name).read(run)
 
 
 def prepare_environment() -> None:
@@ -110,6 +122,75 @@ def check_devices(chips: int, rehearse: bool):
         raise BenchError(f"the cell needs {chips} chips, JAX found "
                          f"{len(devs)}")
     return devs[0]
+
+
+@dataclasses.dataclass
+class Opened:
+    """A cell ready to run: its ``BENCHMARK.json`` entry (``spec``), its
+    configuration and traffic (cut by the model's ``rehearsal`` in a
+    rehearsal), its limits, its driver and model, and the device."""
+    spec: dict
+    config: dict
+    traffic: dict
+    limits: dict
+    driver: types.ModuleType
+    model: types.ModuleType
+    device: object
+
+    def program_ring(self):
+        from repro.core.ring import Ring
+        return Ring(ell=self.config["ring_ell"],
+                    frac=self.config["ring_frac"])
+
+    def run(self, seed: int, seconds: float, window, ring=None,
+            clock_start: float = CLOCK_START):
+        """One run of the driver: its ``Outcome``."""
+        import common
+        return self.driver.run(common.Cell(
+            config=self.config, traffic=self.traffic, model=self.model,
+            seed=seed, seconds=seconds,
+            ring=self.program_ring() if ring is None else ring,
+            window=window, clock_start=clock_start))
+
+    def judge(self, out) -> tuple[bool, dict, list]:
+        """(correct, each limit's number beside it, the limits the run gave
+        no number for)."""
+        import compare
+        checks, missing = compare.checks(out.numbers, self.limits)
+        correct = not missing and out.failed == 0 and compare.passed(checks)
+        return bool(correct), checks, missing
+
+
+def open_cell(workload: str, rehearse: bool) -> Opened:
+    """Find the cell, its driver and its model by name, prepare the
+    environment before JAX starts, and check the devices."""
+    spec, config, traffic, limits = load_cell(workload)
+    prepare_environment()
+    driver, model = resolve(config, traffic)
+    if rehearse:
+        config, traffic = model.rehearsal(config, traffic)
+    device = check_devices(spec["chips"], rehearse)
+    return Opened(spec, config, traffic, limits, driver, model, device)
+
+
+def end_to_end(spec: dict, out, rehearse: bool) -> dict:
+    """The cell's end-to-end metrics as its driver measured them: every
+    entry that names the cell under ``workloads``, and every entry with no
+    ``workloads`` that the driver measures (``Outcome.end_to_end``, and
+    ``setup_s``), so a driver of another kind reports its own.  A
+    rehearsal reports the host-clock ones: CPU timings, never a device's."""
+    values = dict(out.end_to_end, setup_s=out.setup_s)
+    metrics = {}
+    for m in spec["end_to_end"]:
+        if rehearse and m["source"] != "host_clock":
+            continue
+        if m["name"] in values:
+            metrics[m["name"]] = {"value": values[m["name"]],
+                                  "unit": m["unit"]}
+        elif "workloads" in m:
+            raise BenchError(f"the driver measured no {m['name']!r}, which "
+                             "BENCHMARK.json names for this cell")
+    return metrics
 
 
 class Window:
@@ -150,40 +231,29 @@ class Window:
 
 
 def run(args) -> dict:
-    cell, config, traffic, limits = load_cell(args.workload)
-    if args.rehearse:
-        config, traffic = rehearsal_sizes(config, traffic)
-    prepare_environment()
-    import drivers
+    cell = open_cell(args.workload, args.rehearse)
     import trace_reduce
-    dev = check_devices(cell["chips"], args.rehearse)
     import repro  # noqa: F401  (x64, compile cache)
-    from repro.core.ring import Ring
     import jax
 
-    ring = Ring(ell=config["ring_ell"], frac=config["ring_frac"])
+    dev = cell.device
+    ring = None
     if args.control:
-        ring = Ring(**limits["control_ring"])
+        from repro.core.ring import Ring
+        ring = Ring(**cell.limits["control_ring"])
     log_dir = None
     if args.trace and not args.rehearse:
         log_dir = tempfile.mkdtemp(prefix="chipbench-")
     window = Window(log_dir)
     try:
-        out = drivers.DRIVERS[traffic["driver"]](drivers.Cell(
-            config=config, traffic=traffic, seed=args.seed,
-            seconds=args.seconds, ring=ring, window=window,
-            clock_start=CLOCK_START))
+        out = cell.run(args.seed, args.seconds, window, ring=ring)
         reduced = (trace_reduce.reduce(trace_reduce.find_xplane(log_dir))
                    if log_dir is not None else None)
     finally:
         if log_dir is not None:
             shutil.rmtree(log_dir, ignore_errors=True)
 
-    checks = {k: {"value": v, "limit": limits["limits"][k]}
-              for k, v in out.numbers.items()}
-    missing = sorted(set(limits["limits"]) - set(checks))
-    import compare
-    correct = (not missing and out.failed == 0 and compare.passed(checks))
+    correct, checks, missing = cell.judge(out)
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()),
               "memory_peak_bytes": out.memory_peak_bytes}
@@ -195,7 +265,7 @@ def run(args) -> dict:
                              "peaks.json")
         layer = dict(out.layer, trace=reduced, peak_ops_per_s=peaks.get(
             dev.device_kind, {}).get("int8_ops_per_s"))
-        for m in cell["per_layer"]:
+        for m in cell.spec["per_layer"]:
             # a rehearsal reads counts only: no time, rate or share
             if args.rehearse and m["source"] != "program_counter":
                 continue
@@ -206,17 +276,14 @@ def run(args) -> dict:
             device.update(busy_s=reduced["busy_s"],
                           window_s=reduced["window_s"])
             extra["breakdown"] = reduced["breakdown"]
-    elif not args.rehearse:
-        values = dict(out.end_to_end, setup_s=out.setup_s)
-        for m in cell["end_to_end"]:
-            metrics[m["name"]] = {"value": values[m["name"]],
-                                  "unit": m["unit"]}
+    else:
+        metrics = end_to_end(cell.spec, out, args.rehearse)
     if args.rehearse:
         device = {"platform": dev.platform, "rehearsal": True}
-    return {"correct": bool(correct), "attempted": out.attempted,
+    return {"correct": correct, "attempted": out.attempted,
             "failed": out.failed, "metrics": metrics, "device": device,
             **extra, "window_s": out.window_s,
-            "window_batches": out.layer["batches"],
+            "window_batches": out.layer.get("batches"),
             "compiles_in_window": window.compiles,
             "missing_checks": missing, "checks": checks}
 
